@@ -159,6 +159,11 @@ def decay_stats(part: Partition, gamma: float | jax.Array) -> Partition:
     return part._replace(psum=part.psum * gamma, count=part.count * gamma)
 
 
+#: f32 elements of one routing tile ``[rows, M]``: bounds the distance
+#: matrix of :func:`route_into_boxes` whatever the number of points
+_ROUTE_TILE_ELEMS = 1 << 22
+
+
 def route_into_boxes(
     x: jax.Array, lo: jax.Array, hi: jax.Array, active: jax.Array
 ) -> jax.Array:
@@ -167,13 +172,21 @@ def route_into_boxes(
     tails. ``O(n·M)`` elementwise — the one routing rule shared by the
     streaming pass (`engine.streaming._box_route_stats`), the sharded plane
     (`engine.sharded._route_into_boxes`), and the online service's
-    mini-batch merge (`service.session`)."""
+    mini-batch merge (`service.session`). Rows are routed in tiles of
+    ``_ROUTE_TILE_ELEMS // M`` so the ``[n, M]`` matrix never exists whole."""
     lo_ = jnp.where(active[:, None], lo, _BIG)
     hi_ = jnp.where(active[:, None], hi, -_BIG)
-    below = jnp.maximum(lo_[None] - x[:, None, :], 0.0)
-    above = jnp.maximum(x[:, None, :] - hi_[None], 0.0)
-    dist = jnp.max(below + above, axis=-1)  # [n, M] clipped L∞
-    return jnp.argmin(dist, axis=-1).astype(jnp.int32)
+
+    def nearest(xb):
+        below = jnp.maximum(lo_[None] - xb[:, None, :], 0.0)
+        above = jnp.maximum(xb[:, None, :] - hi_[None], 0.0)
+        dist = jnp.max(below + above, axis=-1)  # [rows, M] clipped L∞
+        return jnp.argmin(dist, axis=-1).astype(jnp.int32)
+
+    rows = max(8, _ROUTE_TILE_ELEMS // max(lo.shape[0], 1))
+    if x.shape[0] <= rows:
+        return nearest(x)
+    return jax.lax.map(lambda xr: nearest(xr[None])[0], x, batch_size=rows)
 
 
 def recompute_stats(part: Partition, x: jax.Array) -> Partition:

@@ -33,23 +33,8 @@ __all__ = [
     "shard",
     "named_sharding",
     "logical_to_spec",
-    "shard_map",
 ]
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: ``jax.shard_map`` where it exists,
-    ``jax.experimental.shard_map`` (whose ``check_rep`` is the old name of
-    ``check_vma``) on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
 
 _local = threading.local()
 

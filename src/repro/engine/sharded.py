@@ -7,9 +7,14 @@ Layout (docs/DESIGN.md §3, fault tolerance §5):
   * block stats ``[M, ·]``     — partial per shard, ``psum`` over the data
                                   axes; exact, since sums/counts/min/max are
                                   associative-commutative.
-  * representatives / centroids — tiny (M ≤ thousands): replicated compute,
-                                  identical across shards by construction
-                                  (same psum'd inputs + same PRNG key).
+  * representatives / centroids — tiny (M ≤ thousands): computed on the
+                                  mesh's first device (:func:`_to_lead`)
+                                  and broadcast to every shard when a data
+                                  pass needs them (:func:`_replicate`). A
+                                  Mosaic kernel cannot be partitioned over
+                                  replicated operands, so the driver's
+                                  kernel calls on this state (weighted
+                                  Lloyd, seeding) must see one device.
 
 Points never leave their shard; per-iteration traffic is O(M·d + M·K)
 statistics. The outer loop is :func:`repro.engine.driver.fit_plane` — this
@@ -72,6 +77,22 @@ def _data_axes():
 def n_data_shards() -> int:
     """Number of data-parallel shards on the current mesh (1 when unmeshed)."""
     return math.prod(sh.axis_size(a) for a in sh.batch_axes()) or 1
+
+
+def _to_lead(*arrays):
+    """Move replicated shard_map outputs to the mesh's first device."""
+    mesh = sh.current_mesh()
+    if mesh is None:
+        return arrays
+    return jax.device_put(arrays, mesh.devices.flat[0])
+
+
+def _replicate(*arrays):
+    """Broadcast small lead-device state to every device of the mesh."""
+    mesh = sh.current_mesh()
+    if mesh is None:
+        return arrays
+    return jax.device_put(arrays, NamedSharding(mesh, P()))
 
 
 def shard_points(x: jax.Array) -> jax.Array:
@@ -148,7 +169,7 @@ def _recompute_stats_ok(
     bid_spec = sh.logical_to_spec(("batch",), (n,))
     if alive_rows is None:
         alive_rows = jnp.ones(n, jnp.float32)
-    fn = sh.shard_map(
+    fn = jax.shard_map(
         partial(_stats_body, m=m),
         mesh=mesh,
         in_specs=(row_spec, bid_spec, bid_spec),
@@ -158,7 +179,9 @@ def _recompute_stats_ok(
         ),
         check_vma=False,
     )
-    psum_, count, lo, hi, ok_shards = fn(x, bid, jnp.asarray(alive_rows, jnp.float32))
+    psum_, count, lo, hi, ok_shards = _to_lead(
+        *fn(x, bid, jnp.asarray(alive_rows, jnp.float32))
+    )
     part = part._replace(psum=psum_, count=count, lo=lo, hi=hi, block_id=bid)
     return part, int(ok_shards)
 
@@ -197,14 +220,14 @@ def dist_route_points(
     n, d = x.shape
     row_spec = sh.logical_to_spec(("batch", None), (n, d))  # gather features
     bid_spec = sh.logical_to_spec(("batch",), (n,))
-    fn = sh.shard_map(
+    fn = jax.shard_map(
         _route_body,
         mesh=mesh,
         in_specs=(row_spec, bid_spec, P(None), P(None), P(None), P(None)),
         out_specs=bid_spec,
         check_vma=False,
     )
-    return fn(x, bid, fits, axis, mid, right_row)
+    return fn(x, bid, *_replicate(fits, axis, mid, right_row))
 
 
 def _assign_body(x_loc, c, w_loc, *, impl):
@@ -234,14 +257,15 @@ def dist_assign_step(x: jax.Array, c: jax.Array, w: jax.Array | None = None):
         sums, counts, err, _ = _assign_body(x, c, w, impl=impl)
     else:
         row_spec = sh.logical_to_spec(("batch", None), (n, d))
-        fn = sh.shard_map(
+        fn = jax.shard_map(
             partial(_assign_body, impl=impl),
             mesh=mesh,
             in_specs=(row_spec, P(None, None), sh.logical_to_spec(("batch",), (n,))),
             out_specs=(P(None, None), P(None), P(), sh.logical_to_spec(("batch",), (n,))),
             check_vma=False,
         )
-        sums, counts, err, _ = fn(x, c, w)
+        sums, counts, err, _ = _to_lead(*fn(x, *_replicate(c), w))
+        c = _to_lead(c)[0]
     new_c = jnp.where(
         (counts > 0)[:, None], sums / jnp.maximum(counts, 1e-30)[:, None], c
     )
@@ -325,7 +349,7 @@ class ShardedLloydSession:
             self._step = partial(_pruned_body, impl=impl)
             self._dense_step = partial(_assign_body, impl=impl)
         else:
-            self._seed = sh.shard_map(
+            self._seed = jax.shard_map(
                 partial(_dense_full_body, impl=impl),
                 mesh=mesh,
                 in_specs=(row_spec, P(None, None), vec_spec),
@@ -333,7 +357,7 @@ class ShardedLloydSession:
                            vec_spec, vec_spec, vec_spec),
                 check_vma=False,
             )
-            self._step = sh.shard_map(
+            self._step = jax.shard_map(
                 partial(_pruned_body, impl=impl),
                 mesh=mesh,
                 in_specs=(row_spec, P(None, None), vec_spec, vec_spec, vec_spec,
@@ -342,7 +366,7 @@ class ShardedLloydSession:
                            vec_spec),
                 check_vma=False,
             )
-            self._dense_step = sh.shard_map(
+            self._dense_step = jax.shard_map(
                 partial(_assign_body, impl=impl),
                 mesh=mesh,
                 in_specs=(row_spec, P(None, None), vec_spec),
@@ -352,19 +376,21 @@ class ShardedLloydSession:
 
     def seed(self, c):
         sums, counts, err, n_dist, w2sum, self.assign, self.ub, self.lb = (
-            self._seed(self.x, c, self.w)
+            self._seed(self.x, *_replicate(c), self.w)
         )
+        sums, counts, err, w2sum = _to_lead(sums, counts, err, w2sum)
         return sums, counts, err, w2sum, float(n_dist)
 
     def step(self, c_new, drift):
+        c_new, drift = _replicate(c_new, drift)
         if self.prune:
             sums, counts, n_dist, self.assign, self.ub, self.lb = self._step(
                 self.x, c_new, self.w, self.assign, self.ub, self.lb, drift
             )
-            return sums, counts, float(n_dist)
+            return *_to_lead(sums, counts), float(n_dist)
         sums, counts, _, self.assign = self._dense_step(self.x, c_new, self.w)
         n_dist = jnp.sum((self.w > 0).astype(jnp.float32)) * self.k
-        return sums, counts, float(n_dist)
+        return *_to_lead(sums, counts), float(n_dist)
 
 
 # ------------------------------------------------------- k-means|| session
@@ -414,14 +440,14 @@ class ShardedLLSession:
         self.pending = None
         row_spec = sh.logical_to_spec(("batch", None), (self.n, self.d))
         vec_spec = sh.logical_to_spec(("batch",), (self.n,))
-        self._fold = sh.shard_map(
+        self._fold = jax.shard_map(
             partial(_ll_fold_body, impl=impl),
             mesh=mesh,
             in_specs=(row_spec, vec_spec, vec_spec, P(None, None), P(None)),
             out_specs=(vec_spec, P(), P()),
             check_vma=False,
         )
-        self._weigh = sh.shard_map(
+        self._weigh = jax.shard_map(
             partial(_ll_weight_body, impl=impl),
             mesh=mesh,
             in_specs=(row_spec, vec_spec, P(None, None)),
@@ -487,18 +513,19 @@ def _route_into_boxes(x: jax.Array, part: Partition) -> jax.Array:
     """The shared ``core.partition.route_into_boxes`` clipped-L∞ rule, run
     sharded: each shard routes its local rows against the replicated boxes."""
     mesh = sh.current_mesh()
-
-    def body(x_loc):
-        return part_mod.route_into_boxes(x_loc, part.lo, part.hi, part.active)
-
+    # live rows are the dense prefix [0, n_blocks) (as in the streaming
+    # routing pass): route against it, not the 64·m capacity
+    m_live = min(part.capacity, max(128, -(-int(part.n_blocks) // 128) * 128))
+    boxes = (part.lo[:m_live], part.hi[:m_live], part.active[:m_live])
     if mesh is None:
-        return body(x)
+        return part_mod.route_into_boxes(x, *boxes)
     n, d = x.shape
     row_spec = sh.logical_to_spec(("batch", None), (n, d))
-    return sh.shard_map(
-        body, mesh=mesh, in_specs=(row_spec,),
+    return jax.shard_map(
+        part_mod.route_into_boxes, mesh=mesh,
+        in_specs=(row_spec, P(None, None), P(None, None), P(None)),
         out_specs=sh.logical_to_spec(("batch",), (n,)), check_vma=False,
-    )(x)
+    )(x, *_replicate(*boxes))
 
 
 def _alive_mask_for(

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 __all__ = ["cluster_sums_pallas"]
 
@@ -42,7 +41,8 @@ def _kernel(x_ref, w_ref, a_ref, sums_ref, counts_ref, *, bn: int):
     ).astype(jnp.float32) * wb  # [bn, K] weighted one-hot
 
     sums_ref[...] += jax.lax.dot_general(
-        onehot, xb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, xb, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # [K, d] via MXU
     counts_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T  # [K, 1]
 
@@ -88,7 +88,7 @@ def cluster_sums_pallas(
             jax.ShapeDtypeStruct((kp, dp), jnp.float32),
             jax.ShapeDtypeStruct((kp, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

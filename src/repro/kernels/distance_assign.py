@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 __all__ = ["assign_top2_pallas"]
 
@@ -53,7 +52,8 @@ def _kernel(x_ref, c_ref, assign_ref, d1_ref, d2_ref, *, k_actual: int, bk: int)
     xn = jnp.sum(xb * xb, axis=-1, keepdims=True)  # [bn, 1]
     cn = jnp.sum(cb * cb, axis=-1)  # [bk]
     dots = jax.lax.dot_general(
-        xb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xb, cb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # [bn, bk] on the MXU
     dist = jnp.maximum(xn - 2.0 * dots + cn[None, :], 0.0)
 
@@ -119,7 +119,7 @@ def assign_top2_pallas(
             jax.ShapeDtypeStruct((np_, 1), jnp.float32),
             jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

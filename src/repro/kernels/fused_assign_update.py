@@ -27,6 +27,12 @@ goes to ``bn``. When the accumulator does not fit (``fused_ok=False``),
 ``kernels.ops.assign_update`` selects the two-pass path instead — see the
 ADR for the trade-off.
 
+Precision: every MXU contraction here (and in the other Mosaic kernels)
+runs at ``Precision.HIGHEST``. At the TPU default, f32 operands are rounded
+to bf16; on the SUSY profile (‖x‖² ≈ 1.8e3) that moved the summed error by
+1.6e-3 relative and the assignment of 3% of the rows a float64 reference
+assigns unambiguously, measured on a v5e chip.
+
 Padding contract: padded rows (n → multiple of bn, and streaming chunk
 padding) MUST carry weight 0 — they still get a (garbage, sliced-off)
 assignment, but contribute exactly nothing to sums/counts/err. Padded
@@ -41,8 +47,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 from repro.roofline import analysis
 
 __all__ = [
@@ -95,7 +101,8 @@ def _kernel(
     xn = jnp.sum(xb * xb, axis=-1, keepdims=True)  # [bn, 1]
     cn = jnp.sum(cb * cb, axis=-1)  # [bk]
     dots = jax.lax.dot_general(
-        xb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xb, cb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # [bn, bk] on the MXU
     dist = jnp.maximum(xn - 2.0 * dots + cn[None, :], 0.0)
 
@@ -126,10 +133,12 @@ def _kernel(
             == jax.lax.broadcasted_iota(jnp.int32, (xb.shape[0], kp), 1)
         ).astype(jnp.float32) * wb  # [bn, kp] weighted one-hot, in-registers
         sums_ref[...] += jax.lax.dot_general(
-            onehot, xb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            onehot, xb, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
         )  # [kp, dp] via MXU
         counts_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T  # [kp, 1]
-        err_ref[0, 0] += jnp.sum(wb * d1_ref[...])
+        # (1, 1) vector accumulate: Mosaic cannot store a scalar into VMEM
+        err_ref[...] += jnp.sum(wb * d1_ref[...], keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn", "bk"))
@@ -191,7 +200,7 @@ def fused_assign_update_pallas(
             jax.ShapeDtypeStruct((kp_acc, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # both dims carry VMEM state across steps (row top-2 over j, the
             # cluster accumulators over i and j) — neither is parallel
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -207,11 +216,11 @@ def fused_assign_update_pallas(
 
 
 def _pruned_kernel(
+    flags_ref,
     x_ref,
     w_ref,
     cached_ref,
     act_ref,
-    flag_ref,
     c_ref,
     assign_ref,
     d1_ref,
@@ -226,10 +235,10 @@ def _pruned_kernel(
 ):
     """Drift-bound-pruned variant of ``_kernel`` (ADR 0004).
 
-    ``cached_ref [bn, 1]`` holds the previous assignment, ``act_ref [bn, 1]``
-    the per-row active mask, and ``flag_ref [1, 1]`` the precomputed
-    any-active flag of the whole row block. A fully skipped block runs NO
-    distance work — its rows keep the cached assignment — but every block
+    ``flags_ref [n_blocks]`` (scalar-prefetched into SMEM) holds the
+    precomputed any-active flag of every row block, ``cached_ref [bn, 1]``
+    the previous assignment and ``act_ref [bn, 1]`` the per-row active
+    mask. A fully skipped block runs NO distance work — its rows keep the cached assignment — but every block
     still folds its weighted one-hot statistics contraction with the
     composed assignment, in the identical order the dense kernel uses, so
     the accumulated sums/counts (and hence the next centroids) are
@@ -253,7 +262,7 @@ def _pruned_kernel(
         counts_ref[...] = jnp.zeros_like(counts_ref)
         err_ref[...] = jnp.zeros_like(err_ref)
 
-    blk_active = flag_ref[0, 0] > 0
+    blk_active = flags_ref[i] > 0
     xb = x_ref[...].astype(jnp.float32)  # [bn, dp]
 
     @pl.when(blk_active)
@@ -266,7 +275,8 @@ def _pruned_kernel(
         xn = jnp.sum(xb * xb, axis=-1, keepdims=True)
         cn = jnp.sum(cb * cb, axis=-1)
         dots = jax.lax.dot_general(
-            xb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            xb, cb, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
         )
         dist = jnp.maximum(xn - 2.0 * dots + cn[None, :], 0.0)
         col = j * bk + jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
@@ -297,10 +307,12 @@ def _pruned_kernel(
         ).astype(jnp.float32) * wb  # [bn, kp] weighted one-hot, in-registers
         sums_ref[...] += jax.lax.dot_general(
             onehot, xb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
         )  # [kp, dp] via MXU — identical contraction to the dense kernel
         counts_ref[...] += jnp.sum(onehot, axis=0, keepdims=True).T
-        err_ref[0, 0] += jnp.sum(jnp.where(act, wb * d1_ref[...], 0.0))
+        err_ref[...] += jnp.sum(
+            jnp.where(act, wb * d1_ref[...], 0.0), keepdims=True
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn", "bk"))
@@ -345,31 +357,33 @@ def fused_assign_update_pruned_pallas(
     apad = jnp.pad(assign.astype(jnp.int32), (0, np_ - n))[:, None]
     # padding rows are never active: they keep cached id 0 with weight 0
     actpad = jnp.pad(active.astype(jnp.int32), (0, np_ - n))[:, None]
-    flags = (
-        jnp.max(actpad.reshape(np_ // bn, bn), axis=1, keepdims=True)
-    ).astype(jnp.int32)  # [n_blocks, 1] any-active per row block
+    # any-active per row block, scalar-prefetched into SMEM
+    flags = jnp.max(actpad.reshape(np_ // bn, bn), axis=1)
     cpad = jnp.pad(c, ((0, kp_dist - k), (0, dp - d)))
 
-    grid = (np_ // bn, nk)
+    # with scalar prefetch every index map also receives the flags ref
+    row = pl.BlockSpec((bn, 1), lambda i, j, f: (i, 0))
     assign_o, d1, d2, sums, counts, err = pl.pallas_call(
         functools.partial(_pruned_kernel, k_actual=k, bk=bk, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, dp), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((kp_acc, dp), lambda i, j: (0, 0)),
-            pl.BlockSpec((kp_acc, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(np_ // bn, nk),
+            in_specs=[
+                pl.BlockSpec((bn, dp), lambda i, j, f: (i, 0)),
+                row,
+                row,
+                row,
+                pl.BlockSpec((bk, dp), lambda i, j, f: (j, 0)),
+            ],
+            out_specs=[
+                row,
+                row,
+                row,
+                pl.BlockSpec((kp_acc, dp), lambda i, j, f: (0, 0)),
+                pl.BlockSpec((kp_acc, 1), lambda i, j, f: (0, 0)),
+                pl.BlockSpec((1, 1), lambda i, j, f: (0, 0)),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((np_, 1), jnp.int32),
             jax.ShapeDtypeStruct((np_, 1), jnp.float32),
@@ -378,11 +392,11 @@ def fused_assign_update_pruned_pallas(
             jax.ShapeDtypeStruct((kp_acc, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(xpad, wpad, apad, actpad, flags, cpad)
+    )(flags, xpad, wpad, apad, actpad, cpad)
 
     inf = jnp.float32(jnp.inf)
     d1 = d1[:n, 0]

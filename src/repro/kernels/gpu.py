@@ -10,7 +10,7 @@ concurrently on any SM — so the same seams are restructured here for a
 
   grid = (n/bn,): one program per ``[bn, dp]`` row block. The full padded
   candidate/centroid array is one BlockSpec operand; the program loops over
-  ``[bk, dp]`` tiles of it with dynamic slices (``pl.dslice``), merging the
+  ``[bk, dp]`` tiles of it with dynamic slices (``pl.ds``), merging the
   running top-2 (or min-d²) in loop carry — registers, not memory. Cluster
   statistics cannot be accumulated across programs without atomics (float
   atomics are non-deterministic), so each program writes a per-block
@@ -87,9 +87,7 @@ def _top2_loop(x_ref, c_ref, *, k_actual: int, bk: int, nk):
 
     def body(j, carry):
         d1, d2, a1 = carry
-        cb = pl.load(c_ref, (pl.dslice(j * bk, bk), slice(None))).astype(
-            jnp.float32
-        )  # [bk, dp]
+        cb = c_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)  # [bk, dp]
         cn = jnp.sum(cb * cb, axis=-1)  # [bk]
         dots = jax.lax.dot_general(
             xb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -131,14 +129,8 @@ def _store_stat_partials(
             onehot, xb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bk, dp]
-        pl.store(
-            sums_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk), slice(None)),
-            part[None],
-        )
-        pl.store(
-            counts_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk)),
-            jnp.sum(onehot, axis=0)[None],
-        )
+        sums_ref[pl.ds(0, 1), pl.ds(j * bk, bk), :] = part[None]
+        counts_ref[pl.ds(0, 1), pl.ds(j * bk, bk)] = jnp.sum(onehot, axis=0)[None]
         return 0
 
     jax.lax.fori_loop(0, nk, stats_body, 0)
@@ -196,10 +188,8 @@ def _min_sqdist_kernel(
     xn = jnp.sum(xb * xb, axis=-1, keepdims=True)
 
     def body(j, mind2):
-        cb = pl.load(c_ref, (pl.dslice(j * bl, bl), slice(None))).astype(
-            jnp.float32
-        )
-        vb = pl.load(v_ref, (slice(None), pl.dslice(j * bl, bl)))  # [1, bl]
+        cb = c_ref[pl.ds(j * bl, bl), :].astype(jnp.float32)
+        vb = v_ref[:, pl.ds(j * bl, bl)]  # [1, bl]
         cn = jnp.sum(cb * cb, axis=-1)
         dots = jax.lax.dot_general(
             xb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32

@@ -35,8 +35,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 from repro.roofline import analysis
 
 __all__ = ["min_sqdist_update_pallas"]
@@ -71,7 +71,8 @@ def _kernel(
     xn = jnp.sum(xb * xb, axis=-1, keepdims=True)  # [bn, 1]
     cn = jnp.sum(cb * cb, axis=-1)  # [bl]
     dots = jax.lax.dot_general(
-        xb, cb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xb, cb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # [bn, bl] on the MXU
     dist = jnp.maximum(xn - 2.0 * dots + cn[None, :], 0.0)
     dist = jnp.where(v_ref[...] > 0, dist, _BIG)  # [1, bl] mask broadcast
@@ -85,7 +86,8 @@ def _kernel(
         # The row block's min-d² is final; fold its weighted cost while the
         # state is still in VMEM — this is the fusion.
         wb = w_ref[...].astype(jnp.float32)  # [bn, 1]; padded rows carry 0
-        cost_ref[0, 0] += jnp.sum(wb * out_ref[...])
+        # (1, 1) vector accumulate: Mosaic cannot store a scalar into VMEM
+        cost_ref[...] += jnp.sum(wb * out_ref[...], keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn", "bl"))
@@ -144,7 +146,7 @@ def min_sqdist_update_pallas(
             jax.ShapeDtypeStruct((np_, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # the row min-d² is carried across j and the cost accumulator
             # across i and j — neither grid dimension is parallel
             dimension_semantics=("arbitrary", "arbitrary"),

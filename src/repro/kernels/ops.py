@@ -34,6 +34,7 @@ __all__ = [
     "assign_update_pruned_chunk",
     "backend",
     "cluster_sums",
+    "interpret_mode",
     "min_sqdist_update",
     "min_sqdist_update_chunk",
     "pairwise_sqdist_chunk",
@@ -77,6 +78,12 @@ def pallas_available() -> bool:
     """Whether the current backend has a real (non-interpret) Pallas lowering
     for the clustering kernels: Mosaic on TPU, Triton on GPU."""
     return backend() in _PALLAS_BACKENDS
+
+
+def interpret_mode() -> bool:
+    """Whether the Mosaic kernels run in interpret mode: everywhere but on a
+    TPU. Every Mosaic seam below takes its ``interpret`` flag from here."""
+    return backend() != "tpu"
 
 
 def resolve_impl(impl: str | None) -> str:
@@ -139,7 +146,7 @@ def assign_top2(
             return gpu.assign_top2_gpu(x, c, bn=blk["bn"], bk=blk["bk"])
         from repro.kernels import distance_assign
 
-        interpret = backend() != "tpu"
+        interpret = interpret_mode()
         return distance_assign.assign_top2_pallas(x, c, interpret=interpret)
     return ref.assign_top2(x, c)
 
@@ -215,7 +222,7 @@ def cluster_sums(
     if _resolve(impl) == "pallas" and backend() != "gpu":
         from repro.kernels import cluster_update
 
-        interpret = backend() != "tpu"
+        interpret = interpret_mode()
         return cluster_update.cluster_sums_pallas(
             x, w, assign, num_clusters, interpret=interpret
         )
@@ -262,7 +269,7 @@ def _assign_update_impl(
         from repro.kernels import distance_assign, fused_assign_update
 
         k, d = c.shape
-        interpret = backend() != "tpu"
+        interpret = interpret_mode()
         if fused_assign_update.fused_supported(d, k):
             return AssignUpdate(
                 *fused_assign_update.fused_assign_update_pallas(
@@ -360,7 +367,7 @@ def min_sqdist_update(
             return MinSqDistUpdate(new, cost, n_dist)
         from repro.kernels import min_sqdist_update as msu
 
-        interpret = backend() != "tpu"
+        interpret = interpret_mode()
         new, cost = msu.min_sqdist_update_pallas(
             x, w, cand, cvalid, mind2, interpret=interpret
         )
@@ -445,7 +452,7 @@ def assign_update_pruned(
             return PrunedAssignUpdate(a, d1, d2, sums, counts, err, n_dist)
         from repro.kernels import fused_assign_update
 
-        interpret = backend() != "tpu"
+        interpret = interpret_mode()
         if fused_assign_update.fused_supported(d, k):
             out = PrunedAssignUpdate(
                 *fused_assign_update.fused_assign_update_pruned_pallas(

@@ -121,13 +121,16 @@ def pairwise_sqdist(x: jax.Array, c: jax.Array) -> jax.Array:
     """Squared euclidean distances between rows of ``x [n,d]`` and ``c [K,d]``.
 
     Uses the MXU-friendly decomposition ``|x|^2 - 2 x.c + |c|^2`` with f32
-    accumulation (this is exactly the decomposition the Pallas kernel tiles).
+    accumulation (this is exactly the decomposition the Pallas kernel tiles),
+    at ``Precision.HIGHEST`` like the kernels: the TPU default rounds f32
+    operands to bf16.
     """
     x = x.astype(jnp.float32)
     c = c.astype(jnp.float32)
     xn = jnp.sum(x * x, axis=-1, keepdims=True)  # [n, 1]
     cn = jnp.sum(c * c, axis=-1)  # [K]
-    d2 = xn - 2.0 * (x @ c.T) + cn[None, :]
+    dots = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = xn - 2.0 * dots + cn[None, :]
     return jnp.maximum(d2, 0.0)  # clamp fp cancellation noise
 
 

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from repro.core import baselines, bwkm, metrics
 from repro.data import paper_dataset
 from repro.distributed import dist_bwkm, sharding as sh
-from repro.launch.mesh import make_smoke_mesh
+from repro.launch.mesh import make_data_mesh
 
 
 def main(argv: list[str] | None = None) -> dict:
@@ -30,7 +30,7 @@ def main(argv: list[str] | None = None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--max-iters", type=int, default=25)
     ap.add_argument("--distributed", action="store_true",
-                    help="use the shard_map engine (trivial mesh on 1 CPU)")
+                    help="use the shard_map engine over every attached device")
     ap.add_argument("--compare", action="store_true",
                     help="also run the paper's baselines")
     ap.add_argument("--ckpt-dir", default=None)
@@ -43,7 +43,7 @@ def main(argv: list[str] | None = None) -> dict:
 
     t0 = time.time()
     if args.distributed:
-        mesh = make_smoke_mesh()
+        mesh = make_data_mesh()
         with sh.use_mesh(mesh):
             xs = dist_bwkm.shard_points(x)
             res = dist_bwkm.fit_distributed(key, xs, cfg, checkpoint_dir=args.ckpt_dir)
@@ -86,4 +86,7 @@ def main(argv: list[str] | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -1,6 +1,6 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A function (not a module-level constant) so importing this module never
+Functions (not module-level constants) so importing this module never
 touches jax device state. The dry-run entry point sets
 ``--xla_force_host_platform_device_count=512`` *before* importing jax.
 """
@@ -9,16 +9,13 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_smoke_mesh"]
+__all__ = ["make_data_mesh", "make_production_mesh", "make_smoke_mesh"]
 
 
 def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; every axis here is Auto,
-    # which is also the old default — omit the kwarg on older jax.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -26,6 +23,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return _mesh(shape, axes)
+
+
+def make_data_mesh() -> jax.sharding.Mesh:
+    """Every attached device on the data axis: ``(pod, data, model) =
+    (1, n_devices, 1)`` — the clustering engines shard rows, never features."""
+    return _mesh((1, len(jax.devices()), 1), ("pod", "data", "model"))
 
 
 def make_smoke_mesh() -> jax.sharding.Mesh:

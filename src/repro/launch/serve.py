@@ -289,4 +289,7 @@ def _kv_quantize_report(cfg, params, prompts, baseline_tokens, args) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -167,7 +167,7 @@ def moe_ffn(cfg: ArchConfig, params: dict, x: jax.Array) -> tuple[jax.Array, jax
         y, aux = _moe_local(cfg, params["router"], w1, w3, w2, x.reshape(-1, d), e)
         out = y.reshape(b, s, d)
     elif mode == "ep":
-        fn = sh.shard_map(
+        fn = jax.shard_map(
             partial(_moe_ep_island, cfg, e=e, n_model=n_model, bd=bd),
             mesh=mesh,
             in_specs=(
@@ -182,7 +182,7 @@ def moe_ffn(cfg: ArchConfig, params: dict, x: jax.Array) -> tuple[jax.Array, jax
         )
         out, aux = fn(x, params["router"], w1, w3, w2)
     elif mode == "ep_split":
-        fn = sh.shard_map(
+        fn = jax.shard_map(
             partial(_moe_ep_split_island, cfg, e=e, n_model=n_model, bd=bd),
             mesh=mesh,
             in_specs=(
@@ -200,7 +200,7 @@ def moe_ffn(cfg: ArchConfig, params: dict, x: jax.Array) -> tuple[jax.Array, jax
         )
         out, aux = fn(x, params["router"], w1, w3, w2)
     else:
-        fn = sh.shard_map(
+        fn = jax.shard_map(
             partial(_moe_tp_island, cfg, e=e, bd=bd),
             mesh=mesh,
             in_specs=(

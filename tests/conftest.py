@@ -3,7 +3,3 @@ import os
 # Tests and benches must see exactly ONE device (the dry-run sets its own
 # XLA_FLAGS before importing jax — see src/repro/launch/dryrun.py).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax  # noqa: E402
-
-jax.config.update("jax_threefry_partitionable", True)

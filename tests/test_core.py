@@ -2,6 +2,7 @@
 baselines."""
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -101,9 +102,9 @@ def _jaxpr_eqns_with_shape(jaxpr, shape, acc=None):
         for param in eqn.params.values():
             sub = param if isinstance(param, (tuple, list)) else [param]
             for p in sub:
-                if isinstance(p, jax.core.ClosedJaxpr):
+                if isinstance(p, jax.extend.core.ClosedJaxpr):
                     _jaxpr_eqns_with_shape(p.jaxpr, shape, acc)
-                elif isinstance(p, jax.core.Jaxpr):
+                elif isinstance(p, jax.extend.core.Jaxpr):
                     _jaxpr_eqns_with_shape(p, shape, acc)
     return acc
 

@@ -98,9 +98,10 @@ _MULTIDEV_SCRIPT = textwrap.dedent(
     z = jax.random.randint(kz, (4096,), 0, 5)
     x = (centers[z] + jax.random.normal(kn, (4096, 6))).astype(jnp.float32)
 
-    at = getattr(jax.sharding, "AxisType", None)  # absent on jax 0.4.x
-    kw = {"axis_types": (at.Auto,) * 3} if at is not None else {}
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **kw)
+    mesh = jax.make_mesh(
+        (2, 2, 2), ("pod", "data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 3,
+    )
     with sh.use_mesh(mesh):
         xs = dist_bwkm.shard_points(x)
         assert len(set(d.id for d in xs.devices())) == 8

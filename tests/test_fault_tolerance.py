@@ -207,9 +207,10 @@ _SHARD_LOSS_SCRIPT = textwrap.dedent(
     x = (centers[z] + jax.random.normal(kn, (4096, 6))).astype(jnp.float32)
     cfg = bwkm.BWKMConfig(k=5, max_iters=12)
 
-    at = getattr(jax.sharding, "AxisType", None)
-    kw = {"axis_types": (at.Auto,) * 3} if at is not None else {}
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **kw)
+    mesh = jax.make_mesh(
+        (2, 2, 2), ("pod", "data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 3,
+    )
     with sh.use_mesh(mesh):
         xs = dist_bwkm.shard_points(x)
         n_shards = dist_bwkm.n_data_shards()

@@ -19,13 +19,10 @@ import json
 import os
 import pathlib
 
-# Mirror conftest.py so standalone --regen runs produce the same PRNG stream
-# and backend as the pytest run that consumes the golden file.
+# Mirror conftest.py so standalone --regen runs use the same backend as the
+# pytest run that consumes the golden file.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax  # noqa: E402
-
-jax.config.update("jax_threefry_partitionable", True)
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 

@@ -114,3 +114,16 @@ def test_property_split_axis_separates(n, d, seed):
     if int(part.n_blocks) == 2:
         assert (xs[bid == 0][:, axis] <= mid + 1e-6).all()
         assert (xs[bid == 1][:, axis] > mid - 1e-6).all()
+
+
+@pytest.mark.parametrize("n", [96, 101])
+def test_route_into_boxes_in_tiles_matches_one_pass(monkeypatch, n):
+    """Routing in row tiles (the path every large dataset takes) gives the
+    labels of the one-pass ``[n, M]`` rule, ragged last tile included."""
+    x = gmm(jax.random.PRNGKey(5), n, 3, 4)
+    part = _random_partition(jax.random.PRNGKey(6), x)
+    whole = pm.route_into_boxes(x, part.lo, part.hi, part.active)
+    monkeypatch.setattr(pm, "_ROUTE_TILE_ELEMS", 16 * part.capacity)
+    tiled = pm.route_into_boxes(x, part.lo, part.hi, part.active)
+    np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(part.block_id))

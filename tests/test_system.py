@@ -108,3 +108,23 @@ def test_swa_cache_bounded_for_long_context():
     cfg = configs.get_config("mixtral-8x22b")
     specs = cache_mod.cache_specs(cfg, batch=1, seq_len=524_288)
     assert specs["k"].shape[2] == cfg.window  # 4096, not 524288
+
+
+def test_compile_cache_dir_comes_from_env_or_checkout(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to JAX; without it the entry
+    points cache at ``<checkout>/.jax_cache``, a fixed path."""
+    import pathlib
+
+    from repro.launch import cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = pathlib.Path(__file__).resolve().parent.parent
+    try:
+        assert cache.enable_compile_cache() == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(checkout / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
