@@ -1,0 +1,26 @@
+"""The one traffic generator: reads a mix's data file and builds its schedule.
+
+A mix names its loop by its ``"loop"`` key. The one loop so far:
+
+* ``"fit"``   whole fits back to back through ``repro.BWKM(k=K).fit``; the
+  fits' PRNG keys are a fixed pool of ``key_pool`` keys from the mix's
+  ``structure_seed``, cycled in a fixed order.
+
+Every seed of a mix gets the same work: every run fits the configuration's
+data set from the same keys in the same order, so a window of a given
+length holds the same fits whatever the seed. The pool is larger than a
+window holds, so those fits all start from different keys. The run seed
+draws which of the window's fits the reference checks
+(``chipbench.check``).
+"""
+
+from __future__ import annotations
+
+import jax
+
+from chipbench.data import key_for
+
+
+def fit_keys(mix: dict) -> list[jax.Array]:
+    """The fits' PRNG keys, in the order every run cycles through them."""
+    return [key_for(mix["structure_seed"], 3, j) for j in range(mix["key_pool"])]
