@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api import adapters, engines
 from repro.api.inits import resolve_init
 from repro.api.result import FitResult
@@ -168,17 +169,18 @@ class BWKM:
         """Cluster ``data`` with the selected (or auto-selected) engine."""
         if key is None:
             key = jax.random.PRNGKey(self.seed)
-        name = engines.select_engine(
-            data, self.engine, incore_limit_bytes=self.incore_limit_bytes
-        )
-        res = engines.get_engine(name).fit(
-            key,
-            data,
-            self.config,
-            chunk_size=self.chunk_size,
-            trace_centroids=self.trace,
-            checkpoint_dir=self.checkpoint_dir,
-        )
+        with obs.fit_scope():
+            name = engines.select_engine(
+                data, self.engine, incore_limit_bytes=self.incore_limit_bytes
+            )
+            res = engines.get_engine(name).fit(
+                key,
+                data,
+                self.config,
+                chunk_size=self.chunk_size,
+                trace_centroids=self.trace,
+                checkpoint_dir=self.checkpoint_dir,
+            )
         self.result_ = res
         self.centroids_ = res.centroids
         self.engine_ = name

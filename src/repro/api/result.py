@@ -25,8 +25,9 @@ class FitResult:
     """What every engine reports after ``fit``.
 
     ``metadata`` carries engine-specific extras (block counts, streaming
-    pass statistics, the final ``Partition``, …) without widening the
-    common schema; ``trace`` holds per-iteration snapshots when the caller
+    pass statistics, the final ``Partition``, the ``health`` ledger, the
+    fit's ``counters`` of host syncs and data passes, …) without widening
+    the common schema; ``trace`` holds per-iteration snapshots when the caller
     asked for them (the paper's trade-off curves are plotted from it).
     """
 
@@ -68,6 +69,10 @@ def from_driver_result(res: Any, engine: str) -> FitResult:
     health = getattr(res, "health", None)
     if health is not None and hasattr(health, "as_dict"):
         metadata["health"] = health.as_dict()
+    # the fit's host syncs and passes over all rows (``repro.obs``)
+    counters = getattr(res, "counters", None)
+    if counters is not None:
+        metadata["counters"] = dict(counters)
     return FitResult(
         centroids=res.centroids,
         distances=float(res.distances),

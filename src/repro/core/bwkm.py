@@ -24,6 +24,7 @@ from typing import Any
 
 import jax
 
+from repro import obs
 from repro.core import init_partition
 from repro.core.partition import Partition
 from repro.health import RunHealth
@@ -99,6 +100,8 @@ class BWKMResult:
     # fault/degradation ledger (DESIGN.md §5); None only on legacy paths —
     # the three engines always attach one, all-zero for a clean run
     health: RunHealth | None = None
+    # the fit's ``repro.obs`` counters: {"host_syncs": int, "data_passes": int}
+    counters: dict | None = None
 
 
 def fit_incore(
@@ -118,6 +121,7 @@ def fit_incore(
     """
     from repro.engine import driver, incore
 
-    return driver.fit_plane(
-        key, incore.InCorePlane(x), config, trace_centroids=trace_centroids
-    )
+    with obs.fit_scope():  # the plane's finite-row pass belongs to the fit
+        return driver.fit_plane(
+            key, incore.InCorePlane(x), config, trace_centroids=trace_centroids
+        )

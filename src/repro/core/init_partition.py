@@ -28,6 +28,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import misassignment as mis
 from repro.core import partition as part_mod
 from repro.core.kmeanspp import weighted_kmeanspp
@@ -68,7 +69,7 @@ def starting_partition(
     n = x.shape[0]
     # Worst case one net split per round; typical rounds ~ log2(m').
     for _ in range(4 * m_prime):
-        if int(part.n_blocks) >= m_prime:
+        if obs.pull(part.n_blocks, int) >= m_prime:
             break
         key, k_s, k_c = jax.random.split(key, 3)
         sample_idx = jax.random.randint(k_s, (s,), 0, n)
@@ -132,17 +133,19 @@ def build_initial_partition(
     """Algorithm 2: starting partition (Alg 3), then grow to ``m`` blocks by
     sampling ∝ Alg-4 cutting probabilities."""
     key, k0 = jax.random.split(key)
-    part = starting_partition(k0, x, m_prime, s, capacity)
+    with obs.span("bwkm.init.start"):
+        part = starting_partition(k0, x, m_prime, s, capacity)
     for _ in range(4 * m):
-        if int(part.n_blocks) >= m:
+        if obs.pull(part.n_blocks, int) >= m:
             break
-        key, k_p, k_c = jax.random.split(key, 3)
-        eps_sum = cutting_probabilities_alg4(k_p, part, x, k, s, r)
-        splittable = (part.count > 1) & part.active
-        eps_sum = jnp.where(splittable, eps_sum, 0.0)
-        # All blocks already well assigned for every (S^i, C^i): Pr ≡ 0. The
-        # partition is as good as the samples can tell — stop growing.
-        if not bool(jnp.any(eps_sum > 0)):
-            break
-        part = _sample_split_round(k_c, part, x, eps_sum, m)
+        with obs.span("bwkm.init.grow"):
+            key, k_p, k_c = jax.random.split(key, 3)
+            eps_sum = cutting_probabilities_alg4(k_p, part, x, k, s, r)
+            splittable = (part.count > 1) & part.active
+            eps_sum = jnp.where(splittable, eps_sum, 0.0)
+            # All blocks already well assigned for every (S^i, C^i): Pr ≡ 0.
+            # The partition is as good as the samples can tell — stop growing.
+            if not obs.pull(jnp.any(eps_sum > 0), bool):
+                break
+            part = _sample_split_round(k_c, part, x, eps_sum, m)
     return part

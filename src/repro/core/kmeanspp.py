@@ -15,6 +15,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ref
 
 __all__ = ["forgy", "weighted_kmeanspp", "kmeanspp", "afkmc2"]
@@ -33,7 +34,7 @@ def forgy(key: jax.Array, x: jax.Array, k: int, w: jax.Array | None = None) -> j
     if w is None:
         idx = jax.random.choice(key, n, shape=(k,), replace=False)
     else:
-        if not isinstance(w, jax.core.Tracer) and not bool(jnp.any(w > 0)):
+        if not isinstance(w, jax.core.Tracer) and not obs.pull(jnp.any(w > 0), bool):
             raise ValueError("forgy: no rows with positive weight to seed from")
         # Weight-proportional without replacement via Gumbel top-k on log-weights.
         logw = jnp.where(w > 0, jnp.log(jnp.maximum(w, 1e-30)), -jnp.inf)

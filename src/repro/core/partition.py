@@ -21,6 +21,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = [
     "Partition",
     "BlockStats",
@@ -190,8 +192,10 @@ def route_into_boxes(
 
 
 def recompute_stats(part: Partition, x: jax.Array) -> Partition:
-    """Recompute (psum, count, lo, hi) for all rows from point memberships."""
+    """Recompute (psum, count, lo, hi) for all rows from point memberships:
+    one pass over ``x``, counted as such (``repro.obs``). Call it eagerly."""
     st = block_stats(x, part.block_id, part.capacity)
+    obs.data_pass()
     return part._replace(psum=st.psum, count=st.count, lo=st.lo, hi=st.hi)
 
 
@@ -278,6 +282,7 @@ def split_blocks(part: Partition, x: jax.Array, chosen: jax.Array) -> Partition:
     """In-core split round: plan, route every point, re-tighten all boxes."""
     plan = split_plan(part, chosen)
     new_bid = route_split(x, part.block_id, plan)
+    obs.data_pass()
     out = apply_split_plan(part._replace(block_id=new_bid), plan)
     return recompute_stats(out, x)
 

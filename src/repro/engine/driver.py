@@ -27,6 +27,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import bounds, bwkm as core_bwkm, lloyd as lloyd_mod
 from repro.core import misassignment as mis
 from repro.core import partition as part_mod
@@ -56,19 +57,26 @@ def fit_plane(
     plane's displacement/gap thresholds derive the dataset extent from the
     accumulated block boxes, so no extra data pass is needed.
     """
+    with obs.fit_scope() as fit:
+        return _fit_plane(key, plane, config, trace_centroids, fit.counts)
+
+
+def _fit_plane(key, plane, config, trace_centroids: bool, counters: dict):
     n, d = plane.n_points, plane.dim
     p = config.resolve(n, d)
     k = config.k
 
     key, k_init, k_pp = plane.split_key(key)
-    part = plane.build_partition(k_init, config, p)
+    with obs.span("bwkm.init"):
+        part = plane.build_partition(k_init, config, p)
     # Init cost (Alg 2): r·s·(K-means++ over ≤m reps) + routing; we charge the
     # dominant distance term r · s_rounds · m · K the paper bounds in Thm A.3.
     distances = float(p["r"] * p["s"] * k + p["m"] * k)
 
-    reps, w = part_mod.representatives(part)
-    c = core_bwkm.seed_centroids(config.init, k_pp, reps, w, k)
-    distances += float(int(part.n_blocks)) * k  # seeding distance cost
+    with obs.span("bwkm.seed"):
+        reps, w = part_mod.representatives(part)
+        c = core_bwkm.seed_centroids(config.init, k_pp, reps, w, k)
+        distances += float(obs.pull(part.n_blocks, int)) * k  # seeding distance cost
 
     weighted_errors: list[float] = []
     n_blocks: list[int] = []
@@ -84,79 +92,90 @@ def fit_plane(
 
     it = 0
     for it in range(1, config.max_iters + 1):
-        res = lloyd_mod.weighted_lloyd(
-            reps, w, c,
-            max_iters=config.lloyd_max_iters, epsilon=config.lloyd_epsilon,
-            prune=config.prune,
+        with obs.span("bwkm.round", round=it):
+            with obs.span("bwkm.lloyd", round=it):
+                res = lloyd_mod.weighted_lloyd(
+                    reps, w, c,
+                    max_iters=config.lloyd_max_iters, epsilon=config.lloyd_epsilon,
+                    prune=config.prune,
+                )
+                c = res.centroids
+                distances += obs.pull(res.distances)
+                weighted_errors.append(obs.pull(res.error))
+                n_blocks.append(obs.pull(part.n_blocks, int))
+
+            with obs.span("bwkm.boundary", round=it):
+                eps = mis.misassignment(part, res.d1, res.d2)
+                f_size = obs.pull(jnp.sum(eps > 0), int)
+                boundary_sizes.append(f_size)
+            if trace_centroids:
+                trace.append(
+                    {
+                        "iteration": it,
+                        "distances": distances,
+                        "centroids": obs.pull(c, jax.device_get),
+                        "n_blocks": obs.pull(part.n_blocks, int),
+                        "boundary": f_size,
+                    }
+                )
+
+            # Per-iteration hook BEFORE the stop checks: the sharded plane
+            # checkpoints here, so a restart resumes even from the final round.
+            plane.on_iteration(it, c, part, distances)
+
+            # --- stopping criteria (Section 2.4.2) ---
+            with obs.span("bwkm.stop", round=it):
+                if f_size == 0:
+                    stop_reason = "boundary-empty"  # Theorem 3 applies
+                    break
+                if (
+                    config.distance_budget is not None
+                    and distances >= config.distance_budget
+                ):
+                    stop_reason = "distance-budget"
+                    break
+                if (
+                    displacement_eps_w is not None
+                    and it > 1
+                    and obs.pull(res.max_shift) <= displacement_eps_w
+                ):
+                    stop_reason = "displacement"
+                    break
+                if config.gap_bound_threshold is not None:
+                    gap = obs.pull(bounds.thm2_gap_bound(part, eps, res.d1))
+                    if gap <= config.gap_bound_threshold:
+                        stop_reason = "gap-bound"
+                        break
+                free_rows = p["capacity"] - obs.pull(part.n_blocks, int)
+                if free_rows <= 0:
+                    stop_reason = "capacity"
+                    break
+
+            # --- Step 3: sample |F| blocks ∝ ε with replacement, split, retighten.
+            # The split plan is resolved HERE, once, for every plane — the only
+            # split_plan call site in the engines.
+            with obs.span("bwkm.split", round=it):
+                key, k_cut = jax.random.split(key)
+                chosen = mis.sample_boundary(k_cut, eps, min(f_size, free_rows))
+                plan = part_mod.split_plan(part, chosen)
+            with obs.span("bwkm.route", round=it):
+                part = plane.route_round(part, plan, it)
+            with obs.span("bwkm.reps", round=it):
+                reps, w = part_mod.representatives(part)
+
+    with obs.span("bwkm.result"):
+        return plane.make_result(
+            centroids=c,
+            partition=part,
+            iterations=it,
+            distances=distances,
+            weighted_errors=weighted_errors,
+            n_blocks=n_blocks,
+            boundary_sizes=boundary_sizes,
+            stop_reason=stop_reason,
+            trace=trace,
+            counters=counters,
         )
-        c = res.centroids
-        distances += float(res.distances)
-        weighted_errors.append(float(res.error))
-        n_blocks.append(int(part.n_blocks))
-
-        eps = mis.misassignment(part, res.d1, res.d2)
-        f_size = int(jnp.sum(eps > 0))
-        boundary_sizes.append(f_size)
-        if trace_centroids:
-            trace.append(
-                {
-                    "iteration": it,
-                    "distances": distances,
-                    "centroids": jax.device_get(c),
-                    "n_blocks": int(part.n_blocks),
-                    "boundary": f_size,
-                    **plane.trace_extra(),
-                }
-            )
-
-        # Per-iteration hook BEFORE the stop checks: the sharded plane
-        # checkpoints here, so a restart resumes even from the final round.
-        plane.on_iteration(it, c, part, distances)
-
-        # --- stopping criteria (Section 2.4.2) ---
-        if f_size == 0:
-            stop_reason = "boundary-empty"  # Theorem 3 applies
-            break
-        if config.distance_budget is not None and distances >= config.distance_budget:
-            stop_reason = "distance-budget"
-            break
-        if (
-            displacement_eps_w is not None
-            and it > 1
-            and float(res.max_shift) <= displacement_eps_w
-        ):
-            stop_reason = "displacement"
-            break
-        if config.gap_bound_threshold is not None:
-            gap = float(bounds.thm2_gap_bound(part, eps, res.d1))
-            if gap <= config.gap_bound_threshold:
-                stop_reason = "gap-bound"
-                break
-        free_rows = p["capacity"] - int(part.n_blocks)
-        if free_rows <= 0:
-            stop_reason = "capacity"
-            break
-
-        # --- Step 3: sample |F| blocks ∝ ε with replacement, split, retighten.
-        # The split plan is resolved HERE, once, for every plane — the only
-        # split_plan call site in the engines (acceptance pin, ISSUE 10).
-        key, k_cut = jax.random.split(key)
-        chosen = mis.sample_boundary(k_cut, eps, min(f_size, free_rows))
-        plan = part_mod.split_plan(part, chosen)
-        part = plane.route_round(part, plan, it)
-        reps, w = part_mod.representatives(part)
-
-    return plane.make_result(
-        centroids=c,
-        partition=part,
-        iterations=it,
-        distances=distances,
-        weighted_errors=weighted_errors,
-        n_blocks=n_blocks,
-        boundary_sizes=boundary_sizes,
-        stop_reason=stop_reason,
-        trace=trace,
-    )
 
 
 # --------------------------------------------------- k-means|| (Bahmani 2012)
@@ -212,7 +231,7 @@ def plane_kmeans_parallel(sess: Any, *, rounds: int) -> dict:
     normalisers: list[float] = []
     for rnd in range(1, rounds + 1):
         u, w, mind2, phi = sess.begin_round(rnd)
-        normalisers.append(float(phi))
+        normalisers.append(obs.pull(phi))
         accept = ll_bernoulli(u, w, mind2, sess.l, phi)
         sess.select(rnd, u, accept)
     return sess.finish(tuple(normalisers))

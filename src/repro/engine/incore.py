@@ -16,6 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import bwkm as core_bwkm
 from repro.core import init_partition, kmeanspp
 from repro.core import kmeans_ll as core_ll
@@ -36,13 +37,15 @@ class InCorePlane:
 
     def __init__(self, x: jax.Array):
         health = RunHealth()
-        finite_rows = jnp.all(jnp.isfinite(x), axis=1)
-        n_bad = int(x.shape[0] - jnp.sum(finite_rows))
-        if n_bad:
-            health.quarantined_rows = n_bad
-            x = jnp.asarray(x)[finite_rows]
-            if x.shape[0] == 0:
-                raise ValueError("every input row was non-finite; nothing to cluster")
+        with obs.span("bwkm.plane"):
+            finite_rows = jnp.all(jnp.isfinite(x), axis=1)
+            obs.data_pass()
+            n_bad = obs.pull(x.shape[0] - jnp.sum(finite_rows), int)
+            if n_bad:
+                health.quarantined_rows = n_bad
+                x = jnp.asarray(x)[finite_rows]
+                if x.shape[0] == 0:
+                    raise ValueError("every input row was non-finite; nothing to cluster")
         self.x = x
         self.run_health = health
 
@@ -66,7 +69,8 @@ class InCorePlane:
         )
 
     def extent(self, part: Partition) -> float:
-        return float(
+        obs.data_pass()
+        return obs.pull(
             jnp.linalg.norm(jnp.max(self.x, axis=0) - jnp.min(self.x, axis=0))
         )
 
@@ -74,14 +78,12 @@ class InCorePlane:
         # split_blocks minus the plan (the driver resolves that): route every
         # point, activate the new rows, re-tighten all boxes in one pass.
         new_bid = part_mod.route_split(self.x, part.block_id, plan)
+        obs.data_pass()
         out = part_mod.apply_split_plan(part._replace(block_id=new_bid), plan)
         return part_mod.recompute_stats(out, self.x)
 
     def on_iteration(self, it, c, part, distances) -> None:
         pass
-
-    def trace_extra(self) -> dict:
-        return {}
 
     def make_result(self, **fields) -> core_bwkm.BWKMResult:
         return core_bwkm.BWKMResult(health=self.run_health, **fields)

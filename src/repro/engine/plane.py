@@ -47,6 +47,7 @@ from typing import Any, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.partition import Partition, SplitPlan
 from repro.health import RunHealth
 
@@ -62,7 +63,7 @@ def global_extent(part: Partition) -> float:
     occ = (part.count > 0) & part.active
     lo = jnp.min(jnp.where(occ[:, None], part.lo, _BIG), axis=0)
     hi = jnp.max(jnp.where(occ[:, None], part.hi, -_BIG), axis=0)
-    return float(jnp.linalg.norm(jnp.maximum(hi - lo, 0.0)))
+    return obs.pull(jnp.linalg.norm(jnp.maximum(hi - lo, 0.0)))
 
 
 @runtime_checkable
@@ -102,10 +103,6 @@ class DataPlane(Protocol):
     ) -> None:
         """Per-iteration hook, fired after Lloyd/misassignment and before the
         stop checks (the sharded plane checkpoints here)."""
-        ...
-
-    def trace_extra(self) -> dict:
-        """Plane-specific fields merged into each trace row."""
         ...
 
     def make_result(self, **fields: Any) -> Any:

@@ -696,8 +696,5 @@ class ShardedPlane:
                    "health": self.run_health.as_dict()},
         )
 
-    def trace_extra(self) -> dict:
-        return {}
-
     def make_result(self, **fields) -> core_bwkm.BWKMResult:
         return core_bwkm.BWKMResult(health=self.run_health, **fields)

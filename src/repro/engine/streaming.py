@@ -267,9 +267,6 @@ class StreamingPlane:
     def on_iteration(self, it, c, part, distances) -> None:
         pass
 
-    def trace_extra(self) -> dict:
-        return {"passes": self.stats.passes}
-
     def make_result(self, **fields) -> StreamBWKMResult:
         # A ResilientChunkSource (repro.data.resilient) carries the fault
         # ledger for the whole fit — retries, skipped chunks, quarantined

@@ -3,7 +3,7 @@
 
 The package layering is::
 
-    kernels / data / health / roofline      (primitives)
+    kernels / data / health / obs / roofline (primitives)
         ^
     core                                    (algorithm pieces, in-core ops)
         ^
@@ -21,7 +21,8 @@ resolving the api init registry, the sharded plane's checkpoint hook):
   * ``repro.engine.*`` may import only the primitive layers: ``repro.core``,
     ``repro.kernels``, ``repro.data``, ``repro.distributed.sharding`` (mesh
     topology helpers, not the distributed entry points), ``repro.health``,
-    ``repro.roofline``, and itself. In particular it must NOT import
+    ``repro.obs`` (spans and per-fit counters), ``repro.roofline``, and
+    itself. In particular it must NOT import
     ``repro.api`` / ``repro.service`` / ``repro.vq`` / ``repro.streaming`` /
     ``repro.train`` or the ``distributed.dist_*`` entry points — the engines
     sit BELOW every facade.
@@ -51,6 +52,7 @@ RULES: dict[str, tuple] = {
             "repro.data",
             "repro.distributed.sharding",
             "repro.health",
+            "repro.obs",
             "repro.roofline",
             "repro.engine",
         ],
