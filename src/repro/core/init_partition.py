@@ -13,9 +13,10 @@ Algorithm 2 alternates Algorithm-4 probabilities with ∝-sampled splits
 until ``m`` blocks exist.
 
 Deviation (documented in DESIGN.md §8): we keep the full-dataset point
-routing up to date during construction (one O(n) gather/compare per split
-round) instead of a single O(n·m) pass at the end — same asymptotics,
-single code path.
+routing up to date during construction (one compiled O(n·d) routing pass
+per split round) instead of a single pass at the end. Each round's split
+planes come from the tight boxes of all rows, so every round needs the
+routed rows: end-only routing would give a different partition.
 
 Paper defaults (Section 2.4.1): m = 10·√(K·d), s = √n, r = 5, and our
 m' = max(K+1, m/10) (the paper requires K < m' < m but fixes no value).

@@ -3,8 +3,9 @@
 A partition lives in *fixed-capacity* arrays so every BWKM step is a static
 XLA program: ``max_blocks`` rows, a per-row active mask, and a per-point
 ``block_id``. Splits consume preallocated rows (parent row becomes the left
-child, a fresh row the right child) and point routing is repaired with one
-vectorised gather + compare against the split plane — no tree traversal.
+child, a fresh row the right child) and point routing is repaired in one
+compiled pass (one packed plan lookup per row, a compare against the split
+plane) — no tree traversal.
 
 Blocks are recorded by their *tight bounding boxes* (the paper recomputes the
 smallest bounding box of every subset when updating the partition in Step 3 of
@@ -255,16 +256,34 @@ def split_plan(part: Partition, chosen: jax.Array) -> SplitPlan:
     return SplitPlan(fits, axis, mid, right_row, jnp.sum(fits.astype(jnp.int32)))
 
 
+@jax.jit
 def route_split(x: jax.Array, bid: jax.Array, plan: SplitPlan) -> jax.Array:
     """Repair point memberships after a split round: a member of a split block
-    goes right iff ``x[axis] > mid``. One vectorised gather + compare — no
-    tree traversal; works on any subset of the dataset (shard, chunk)."""
-    p_split = plan.fits[bid]  # [n]
-    p_axis = plan.axis[bid]
-    p_mid = plan.mid[bid]
-    p_val = jnp.take_along_axis(x, p_axis[:, None], axis=1)[:, 0]
-    goes_right = p_split & (p_val > p_mid)
-    return jnp.where(goes_right, plan.right_row[bid], bid)
+    goes right iff ``x[axis] > mid``. One compiled pass over the rows, with no
+    tree traversal; works on any subset of the dataset (shard, chunk).
+
+    The plan is looked up once per row, from one packed ``[M, 3]`` int32
+    table (split axis, or -1 for a block that does not split; ``mid``'s
+    bits; right child row). The split coordinate is picked densely, by a
+    compare-and-select over the row's ``d`` columns, so ``x`` is read once in
+    its own layout and never gathered per row. The pick is a max against
+    ``-inf``, which passes the chosen value through unchanged, so the routing
+    is bit for bit that of a per-row gather of ``x[axis]``.
+    """
+    table = jnp.stack(
+        [
+            jnp.where(plan.fits, plan.axis, -1),
+            jax.lax.bitcast_convert_type(plan.mid, jnp.int32),
+            plan.right_row,
+        ],
+        axis=1,
+    )
+    row = table[bid]  # [n, 3]: the one per-row lookup
+    p_axis, p_right = row[:, 0], row[:, 2]
+    p_mid = jax.lax.bitcast_convert_type(row[:, 1], jnp.float32)
+    on_axis = jnp.arange(x.shape[1]) == p_axis[:, None]  # all false when -1
+    p_val = jnp.max(jnp.where(on_axis, x, -jnp.inf), axis=1)
+    return jnp.where((p_axis >= 0) & (p_val > p_mid), p_right, bid)
 
 
 def apply_split_plan(part: Partition, plan: SplitPlan) -> Partition:

@@ -208,11 +208,11 @@ def dist_route_points(
     x: jax.Array, bid: jax.Array, fits, axis, mid, right_row
 ) -> jax.Array:
     """Repair local block ids after a split round — ``partition.route_split``
-    applied per shard (pure local gather+compare).
+    applied per shard (a local plan lookup and compare, no communication).
 
-    Feature sharding caveat: the split coordinate lives on one model shard;
-    we broadcast the needed column via the replicated-stat path (axis/mid are
-    replicated; x columns are gathered only for the split axes).
+    Feature sharding caveat: the split coordinate lives on one model shard,
+    so the rows are gathered over ``model`` before routing (the plan is
+    replicated).
     """
     mesh = sh.current_mesh()
     if mesh is None:

@@ -19,7 +19,7 @@ Pass structure per outer iteration:
   * weighted Lloyd + misassignment run on the M-row representative set —
     no data pass at all;
   * a split round is ONE streaming pass: each chunk's memberships are
-    repaired against the split plan (gather + compare) and its block
+    repaired against the split plan (`route_split`) and its block
     statistics are re-accumulated in the same jitted program.
 
 The chunk programs live in :mod:`repro.engine.streaming`; this module keeps

@@ -127,3 +127,97 @@ def test_route_into_boxes_in_tiles_matches_one_pass(monkeypatch, n):
     tiled = pm.route_into_boxes(x, part.lo, part.hi, part.active)
     np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(part.block_id))
+
+
+# ------------------------------------------------- split-round routing
+def _route_split_by_gather(x, bid, plan):
+    """The per-row gather formulation ``route_split`` replaced: four scalar
+    plan lookups and a gather of ``x[axis]`` per row."""
+    p_axis = plan.axis[bid]
+    p_val = jnp.take_along_axis(x, p_axis[:, None], axis=1)[:, 0]
+    goes_right = plan.fits[bid] & (p_val > plan.mid[bid])
+    return jnp.where(goes_right, plan.right_row[bid], bid)
+
+
+def _routing_case(d, n=1001, capacity=48):
+    """A partition one split round away from full, and a plan whose rows
+    sit on every edge: members exactly on ``mid`` (they go left), ``-0.0``
+    and ``+0.0`` coordinates against a ``0.0`` plane, blocks that are not
+    chosen, and chosen blocks whose right child would exceed capacity."""
+    x = gmm(jax.random.PRNGKey(d), n, d, 5)
+    part = pm.create_partition(x, capacity)
+    grown = capacity - capacity // 4
+    while int(part.n_blocks) < grown:
+        splittable = part.active & (part.count > 1)
+        first = jnp.cumsum(splittable) <= grown - part.n_blocks
+        part = pm.split_blocks(part, x, splittable & first)
+    chosen = part.active & (jnp.arange(capacity) % 5 != 0)
+    plan = pm.split_plan(part, chosen)
+    too_late = chosen & (part.count > 1) & ~plan.fits
+    assert bool(jnp.any(too_late)) and bool(jnp.any(plan.fits))
+    bid = part.block_id
+    rows = np.arange(0, n, 7)
+    col = np.asarray(plan.axis)[np.asarray(bid)[rows]]
+    x = x.at[rows, col].set(plan.mid[bid[rows]])  # exactly on the plane
+    zero_blocks = jnp.arange(capacity) % 3 == 0
+    plan = plan._replace(mid=jnp.where(zero_blocks, 0.0, plan.mid))
+    signed = np.arange(3, n, 11)
+    col = np.asarray(plan.axis)[np.asarray(bid)[signed]]
+    x = x.at[signed, col].set(jnp.where(signed % 2 == 0, -0.0, 0.0))
+    x = x.at[signed[::3]].set(-0.0)  # whole rows of -0.0
+    return x, bid, plan
+
+
+@pytest.mark.parametrize("where", ["jit", "streaming"])
+@pytest.mark.parametrize("d", [3, 19, 128])
+def test_route_split_matches_the_gather_formulation_bit_for_bit(d, where):
+    x, bid, plan = _routing_case(d)
+    want = np.asarray(_route_split_by_gather(x, bid, plan))
+    if where == "jit":
+        got = jax.jit(pm.route_split)(x, bid, plan)
+    else:
+        from repro.engine.streaming import _split_route_stats
+
+        n, cs = x.shape[0], 1024 + 128  # padded like a streaming chunk
+        xp = jnp.zeros((cs, d), x.dtype).at[:n].set(x)
+        bp = jnp.zeros((cs,), jnp.int32).at[:n].set(bid)
+        got, _ = _split_route_stats(xp, bp, n, plan, m=plan.fits.shape[0])
+        got = got[:n]
+    np.testing.assert_array_equal(np.asarray(got), want)
+    moved = want != np.asarray(bid)
+    assert moved.any() and not moved.all()
+
+
+def test_route_split_is_one_program_with_no_gather_into_x():
+    n, d, m = 4096, 19, 256
+    x = jax.ShapeDtypeStruct((n, d), jnp.float32)
+    bid = jax.ShapeDtypeStruct((n,), jnp.int32)
+    plan = pm.SplitPlan(
+        jax.ShapeDtypeStruct((m,), jnp.bool_), jax.ShapeDtypeStruct((m,), jnp.int32),
+        jax.ShapeDtypeStruct((m,), jnp.float32), jax.ShapeDtypeStruct((m,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+    )
+    text = pm.route_split.lower(x, bid, plan).as_text()
+    ops = [line for line in text.splitlines() if "stablehlo.gather" in line
+           or "stablehlo.transpose" in line]
+    assert sum("stablehlo.gather" in line for line in ops) <= 1
+    assert not [line for line in ops if f"tensor<{n}x{d}xf32>" in line]
+    # an eager call is one dispatch: the whole round traces to one jit
+    eqns = jax.make_jaxpr(pm.route_split)(x, bid, plan).eqns
+    assert len(eqns) == 1 and eqns[0].params["name"] == "route_split"
+
+
+def test_in_core_route_round_routes_in_one_call(monkeypatch):
+    from repro.engine.incore import InCorePlane
+
+    x = gmm(jax.random.PRNGKey(9), 600, 4, 3)
+    plane = InCorePlane(x)
+    part = pm.create_partition(plane.x, 32)
+    plan = pm.split_plan(part, part.active)
+    calls = []
+    route = pm.route_split
+    monkeypatch.setattr(pm, "route_split", lambda *a: calls.append(a) or route(*a))
+    out = plane.route_round(part, plan, 1)
+    assert len(calls) == 1
+    ref = pm.split_blocks(part, x, part.active)
+    np.testing.assert_array_equal(np.asarray(out.block_id), np.asarray(ref.block_id))

@@ -1,4 +1,5 @@
-"""The Mosaic kernels compile for a TPU v5e chip at the main path's widths.
+"""The Mosaic kernels compile for a TPU v5e chip at the main path's widths,
+and the split-round routing compiles to one pass that reads ``x`` in place.
 
 Nothing runs: each kernel is lowered from shapes alone with
 ``interpret=False`` and compiled by the TPU compiler for a described (not
@@ -16,6 +17,7 @@ import pytest
 from jax.experimental import pallas as pl
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import partition
 from repro.kernels import distance_assign, fused_assign_update, min_sqdist_update
 from repro.roofline import analysis
 
@@ -118,3 +120,23 @@ def test_planned_vmem_is_admitted_by_the_compiler(one_chip):
 
     compiled = _compile(copy, one_chip, ((4 * rows, 128), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n,d,m", [(5_000_000, 19, 14528), (434_874, 3, 3328)])
+def test_route_split_reads_x_in_place_at_the_fit_cells_sizes(one_chip, n, d, m):
+    """The split-round routing at the SUSY and 3RN cells' sizes, as the TPU
+    compiler optimises it: one gather (the packed plan lookup) and no copy,
+    transpose or gather of the ``[n, d]`` rows."""
+    compiled = _compile(
+        lambda x, b, f, a, mid, r: partition.route_split(
+            x, b, partition.SplitPlan(f, a, mid, r, jnp.sum(f))
+        ),
+        one_chip,
+        ((n, d), jnp.float32), ((n,), jnp.int32), ((m,), jnp.bool_),
+        ((m,), jnp.int32), ((m,), jnp.float32), ((m,), jnp.int32),
+    )
+    lines = compiled.as_text().splitlines()
+    assert sum(" gather(" in line for line in lines) == 1
+    rows = f"f32[{n},{d}]"
+    for op in (" copy(", " transpose(", " gather("):
+        assert not [line for line in lines if op in line and rows in line.split(op, 1)[1]]
