@@ -40,21 +40,25 @@ from chipbench.data import rng_for
 #: the precision below the configuration's float32 at HIGHEST: 3-pass bf16
 CONTROL_PRECISION = lax.Precision.HIGH
 
-#: fits of one run whose partitions are recounted
+#: fits of one run's first ``quality_fits`` whose partitions are recounted,
+#: besides the window's last fit (``loops.fit_window``)
 CHECKED_FITS = 3
 
 
-def fit_numbers(x: jax.Array, results: list, seed: int, *, control: bool = False,
-                picks: list[int] | None = None) -> dict[str, float]:
-    """The numbers, each the worst over ``CHECKED_FITS`` of the window's fits
-    drawn from ``seed``, or over the fits ``picks`` names."""
-    x_host = np.asarray(x)
-    xabs = float(np.max(np.abs(x_host)))
-    if picks is None:
-        picks = rng_for(seed, 9).choice(len(results), size=min(CHECKED_FITS, len(results)),
-                                        replace=False).tolist()
+def checked_fits(seed: int, quality_fits: int) -> list[int]:
+    """The fits a run checks, drawn from ``seed`` before its window among
+    its first ``quality_fits``, which every run fits from the same keys."""
+    return sorted(rng_for(seed, 9).choice(quality_fits, size=min(CHECKED_FITS, quality_fits),
+                                          replace=False).tolist())
+
+
+def fit_numbers(x: jax.Array, results: list, picks: list[int], *,
+                control: bool = False) -> dict[str, float]:
+    """The numbers, each the worst over the fits of ``results`` that
+    ``picks`` names."""
+    xabs = float(jnp.max(jnp.abs(x)))
     out = {"count_err": 0.0, "box_err": 0.0, "psum_gap": 0.0, "err_gap": 0.0}
-    for i in sorted(picks):
+    for i in picks:
         r = results[i]
         part = r.metadata["partition"]
         m = part.lo.shape[0]
@@ -66,12 +70,12 @@ def fit_numbers(x: jax.Array, results: list, seed: int, *, control: bool = False
             stats = (part.psum, part.count, part.lo, part.hi)
         psum_p, count_p = (np.asarray(a, np.float64) for a in stats[:2])
         lo_p, hi_p = (np.asarray(a, np.float32) for a in stats[2:])
-        psum_r, count_r, lo_r, hi_r = reference.block_stats_f64(x_host, bid, m)
+        psum_r, count_r, lo_r, hi_r = reference.block_stats(x, part.block_id, m)
         live = active & (count_r > 0)
         count_err = int(np.sum(count_p[active] != count_r[active]))
         count_err += int(np.sum(~active[np.clip(bid, 0, m - 1)] | (bid < 0) | (bid >= m)))
-        box_err = int(np.sum(np.any(lo_p[live] != lo_r[live].astype(np.float32), axis=1)
-                             | np.any(hi_p[live] != hi_r[live].astype(np.float32), axis=1)))
+        box_err = int(np.sum(np.any(lo_p[live] != lo_r[live], axis=1)
+                             | np.any(hi_p[live] != hi_r[live], axis=1)))
         gap = np.max(np.abs(psum_p[live] - psum_r[live]), axis=1) / (count_r[live] * xabs)
         # the representatives as the fit holds them
         held = live & (count_p > 0)

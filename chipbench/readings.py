@@ -7,8 +7,9 @@ a fault of ``chipbench.faults`` planted under it), then every compared
 number twice for each fit of the window: from what the program produced,
 and from the control (the reference at the precision below the
 configuration's, put in the program's place). A run checks the fits that
-its seed draws from the window; every run's window holds the same fits, so
-reading each of them reads what any seed can. One JSON line per fit on
+its seed draws among its window's first ``quality_fits``, which every run
+fits from the same keys, and its window's last; this reads every fit of
+its window, so it keeps every fit's partition. One JSON line per fit on
 stdout. Benchmark runs never run this.
 """
 
@@ -50,8 +51,8 @@ def main(argv=None) -> int:
     results = win["results"]
     for i, r in enumerate(results):
         t0 = time.perf_counter()
-        numbers = {side: check.fit_numbers(state["x"], results, 0,
-                                           control=side == "control", picks=[i])
+        numbers = {side: check.fit_numbers(state["x"], results, [i],
+                                           control=side == "control")
                    for side in ("program", "control")}
         print(json.dumps({
             "workload": cell["name"], "fault": args.fault, "fit": i, **numbers,

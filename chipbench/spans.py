@@ -37,16 +37,6 @@ class Spans:
             with self._lock:
                 self.items.append((name, t0, t1))
 
-    def add(self, name: str, t0: float, t1: float) -> None:
-        with self._lock:
-            self.items.append((name, t0, t1))
-
-    def durations(self, name: str, start: float = float("-inf"),
-                  end: float = float("inf")) -> list[float]:
-        """Durations of the spans called ``name`` that began in [start, end)."""
-        with self._lock:
-            return [b - a for n, a, b in self.items if n == name and start <= a < end]
-
 
 class CompileCounter:
     """Counts XLA backend compilations in this process (``count``) and the

@@ -21,7 +21,8 @@ def test_manifest_has_no_faults():
 def test_each_cell_finds_its_files(cell):
     w = manifest.workload(BENCH, cell)
     cfg = manifest.config(BENCH, w["config"])
-    assert {"n", "d", "k", "generator", "reduced", "assumed"} <= set(cfg)
+    assert {"n", "d", "k", "generator", "reduced", "assumed", "rehearsal"} <= set(cfg)
+    assert set(cfg["rehearsal"]) <= {"n", "k"} and cfg["rehearsal"]["n"] <= cfg["n"]
     assert manifest.traffic(w["traffic"])["loop"] == "fit"
     assert manifest.limits(cell)["limits"]
     reported = {m["name"] for m in manifest.end_to_end_for(BENCH, cell)}

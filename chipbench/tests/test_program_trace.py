@@ -126,9 +126,10 @@ def test_without_benchmark_spans_the_program_fits_are_the_windows(pd):
         (2, 98), (50, 60), (101, 159)]
 
 
-def _ctx(traced_fits, results=()):
+def _ctx(traced_fits, results=(), quality_fits=4):
     return {"trace": {"busy_s": 1.0, "window_s": 2.0}, "cell": {"name": "3rn_k9.fit"},
-            "traced_fits": traced_fits, "window": {"results": list(results)}}
+            "traced_fits": traced_fits,
+            "window": {"results": list(results), "quality_fits": quality_fits}}
 
 
 @pytest.fixture
@@ -161,18 +162,25 @@ def test_trace_readers_read_nothing_from_a_program_without_spans(monkeypatch):
 
 
 def test_counter_readers():
-    def fit(counters):
+    def fit(counters, distances=0.0):
         meta = {"counters": counters} if counters else {}
-        return types.SimpleNamespace(metadata=meta)
+        return types.SimpleNamespace(metadata=meta, distances=distances)
 
-    results = [fit({"host_syncs": 80, "data_passes": 40}),
-               fit({"host_syncs": 90, "data_passes": 44})]
+    results = [fit({"host_syncs": 80, "data_passes": 40}, 100.0),
+               fit({"host_syncs": 90, "data_passes": 44}, 300.0)]
+    # fits past the window's first quality_fits are not read: a faster
+    # program's longer window reads the same fits
+    later = [fit({"host_syncs": 1000, "data_passes": 1000}, 1e9), fit(None)]
     syncs = manifest.metric_reader("host_syncs_per_fit").read
     passes = manifest.metric_reader("data_passes_per_fit").read
-    assert syncs(_ctx(1, results)) == 85
-    assert passes(_ctx(1, results)) == 42
+    distances = manifest.metric_reader("fit_distances").read
+    assert syncs(_ctx(1, results + later, quality_fits=2)) == 85
+    assert passes(_ctx(1, results + later, quality_fits=2)) == 42
+    assert distances(_ctx(1, results + later, quality_fits=2)) == 200.0
+    assert syncs(_ctx(1, results + later, quality_fits=3)) == 390
     assert syncs(_ctx(1, [fit(None)])) is None
     assert passes(_ctx(1, [])) is None
+    assert distances(_ctx(1, [])) is None
 
 
 def test_table_lists_every_span_and_outside(result):

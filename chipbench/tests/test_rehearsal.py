@@ -1,8 +1,9 @@
 """CPU rehearsal of each cell at a tiny size, the kernels in interpret mode.
 
-The run skips the look for a chip, shrinks the configuration, and prints a
-last line of the result's shape marked ``"rehearsal": true``, with no
-metric: a CPU run never reports a device metric.
+The run skips the look for a chip, shrinks the configuration to the sizes
+under its file's ``"rehearsal"`` key, and prints a last line of the
+result's shape marked ``"rehearsal": true``, with no metric: a CPU run
+never reports a device metric.
 
     python -m pytest chipbench/tests/test_rehearsal.py
 """
@@ -15,7 +16,6 @@ from chipbench import manifest, run
 from repro.kernels import ops
 
 BENCH = manifest.load_manifest()
-TINY = {"susy_k27": {"n": 8000}, "3rn_k9": {"n": 6000}}
 
 
 @pytest.fixture
@@ -29,7 +29,8 @@ def rehearse(cell: str, seconds: float = 1.0, fault: str | None = None) -> dict:
     w = manifest.workload(BENCH, cell)
     args = run.parse(["--workload", cell, "--seed", str(2**33 + 17),
                       "--seconds", str(seconds), "--trace", "0"])
-    return run.run(args, rehearsal={"config": TINY[w["config"]]}, fault=fault)
+    cfg = manifest.config(BENCH, w["config"])
+    return run.run(args, rehearsal={"config": cfg["rehearsal"]}, fault=fault)
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
